@@ -3,16 +3,17 @@
 Three numbers matter:
 
   * DISPATCHES — a campaign wave carries all surviving cells on the
-    vmapped cell axis, so a G x S grid pays one plan's round dispatches
+    mapped cell axis, so a G x S grid pays one plan's round dispatches
     where the naive loop pays one plan PER CELL (the paper's batch-model
     metric: ceil(K/W) batches, here further divided by the grid size);
   * KNOCKOUT — cells failed by the cheap phases (seam check, screening
     wave) never reach the expensive confirmation wave;
-  * wall clock — honest caveat: on a single CPU device the vmapped cell
-    axis SERIALIZES, so batched wall ~= per-cell wall (plus the seam
-    phase the naive loop doesn't run); the dispatch-count ratio is what
-    turns into wall clock on hardware with a real parallel axis, which
-    is why ``wave_makespan``'s model ratio is reported alongside.
+  * wall clock — honest caveat: the cell axis is a ``lax.map``, so
+    its cells run one after another inside a dispatch and batched wall
+    ~= per-cell wall (plus the seam phase the naive loop doesn't run);
+    the dispatch-count ratio is what turns into wall clock where host
+    round trips dominate, which is why ``wave_makespan``'s model ratio
+    is reported alongside.
 
 Both strategies get a fresh PoolSession and full compile-cache sharing
 (one trace serves every cell either way) — the measured gap isolates

@@ -8,7 +8,7 @@ rounds instead of a whole battery. Rows report rounds-to-verdict for the
 adaptive early-stopping run vs the rounds a full battery executes, plus
 the wall-clock of each. A final row sweeps EVERY registered generator at
 crush scale (one multi-generator fan-out dispatch per round, failed
-generators dropping out of the vmapped axis) and checks the early-stopped
+generators dropping out of the mapped axis) and checks the early-stopped
 verdict agrees with the full-battery verdict for each.
 """
 from __future__ import annotations

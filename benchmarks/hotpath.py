@@ -38,6 +38,7 @@ def collect() -> dict:
     import jax
     import numpy as np
 
+    from repro.common.compat import x64
     from repro.core import pool
     from repro.core.battery import build_battery
     from repro.core.pool import run_sequential
@@ -52,13 +53,13 @@ def collect() -> dict:
         "weight": dict(n=16384), "serial2d": dict(n=8192, d=32),
         "collision": dict(n=8192, kbits=14), "rank": dict(n_mats=512),
     }
-    with G.x64():
+    with x64():
         bits = G.splitmix64_block(1, 1, 262144)
     kernels = {}
     for fam, kw in cases.items():
-        ref = jax.jit(lambda b, f=B.get_kernel(fam, "reference"),
+        ref = jax.jit(lambda b, f=B.route(fam, "reference")[0],
                       k=kw: f(b, **k))
-        acc = jax.jit(lambda b, f=B.get_kernel(fam, "accelerated"),
+        acc = jax.jit(lambda b, f=B.route(fam, "accelerated", **kw)[0],
                       k=kw: f(b, **k))
         kernels[fam] = {"params": kw,
                         "reference_us": round(_t(ref, bits), 1),
@@ -81,7 +82,12 @@ def collect() -> dict:
     report["block_ratio"] = blocks
 
     # -- jump-ahead generators vs their scan twins -------------------------
-    from repro.common.compat import under_x64
+    def gen_jit(fn):
+        @jax.jit
+        def block(seed):
+            with x64():
+                return fn(seed, 1, n)
+        return block
 
     gens = {"bitexact": {}, "us": {}}
     n = 65536
@@ -89,8 +95,7 @@ def collect() -> dict:
         jump = G.GENERATORS[name]
         # seed is a RUNTIME argument — with everything static XLA
         # constant-folds the whole block and the timing is fiction
-        jj = under_x64(jax.jit(lambda seed, fn=jump: fn(seed, 1, n)))
-        ss = under_x64(jax.jit(lambda seed, fn=scan: fn(seed, 1, n)))
+        jj, ss = gen_jit(jump), gen_jit(scan)
         gens["bitexact"][name] = bool(
             (np.asarray(jj(3)) == np.asarray(ss(3))).all())
         gens["us"][name] = {"jump": round(_t(jj, 3), 1),

@@ -1,5 +1,9 @@
 """Benchmark harness — one module per paper table/figure. Prints
-``name,us_per_call,derived`` CSV (brief contract)."""
+``name,us_per_call,derived`` CSV (brief contract). Every module runs in
+this one process (on an accelerator, the process that holds the chips):
+
+  PYTHONPATH=src python -m benchmarks.run
+"""
 from __future__ import annotations
 
 import sys
@@ -7,6 +11,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.common.runtime import cpu_devices
+    cpu_devices(8)          # the pool rows' width on a host without chips
     from benchmarks import (accuracy, batch_model, battery_times,
                             campaign, early_stop, elastic, hotpath,
                             kernel_bench, lm_step, submit_overhead)

@@ -1,48 +1,19 @@
-"""JAX version compatibility shims.
+"""The one place the battery turns on 64-bit tracing.
 
-The repo targets the modern JAX surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``, scoped
-``enable_x64`` inside traces). The pinned container ships jax 0.4.37,
-where those spell differently — and where a scoped ``enable_x64`` inside
-a jitted trace mis-lowers (u64 constants canonicalize to u32 at lowering
-time, outside the context). Everything that needs to differ by version
-lives here so the rest of the codebase writes against one API.
+Only the generators (``rng/generators.py``) need 64-bit integers: their
+states, counters and jump-ahead ladders are uint64. ``x64()`` scopes
+64-bit tracing to exactly that code. Everything else — the test kernels,
+the Pallas kernels and the float statistics — traces in JAX's default 32
+bits, so no float64 op and no 64-bit Pallas index reaches the device.
+The scope may open and close inside a jitted trace: a program traced in
+32 bits keeps the uint64 ops traced under it (``pool._job_fn``).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (<=0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def make_mesh(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` without the ``axis_types`` kwarg on old jax."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def under_x64(fn):
-    """Call ``fn`` with the x64 context ambient for the WHOLE call —
-    trace, lowering, and execution see one consistent dtype config.
-    On jax<=0.4.x a scoped ``enable_x64`` that closes mid-trace truncates
-    uint64 constants during lowering; entering it around the outer call
-    (idempotent when already active) sidesteps that while keeping the
-    scoped uses in traced code valid on newer jax."""
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        with jax.experimental.enable_x64():
-            return fn(*args, **kwargs)
-    return wrapper
+def x64():
+    """Context manager: trace and run the enclosed code with 64-bit
+    dtypes enabled (``jax.enable_x64(True)``)."""
+    return jax.enable_x64(True)

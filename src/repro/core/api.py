@@ -29,14 +29,14 @@ The paper's orchestration layer (`master`/`makesub`/`condor_submit`/
                    reports the sequential PASS/FAIL/UNDECIDED decision
                    after any round, ``cancel()`` drops pending rounds
                    (condor_rm). A spec with several generators fans out
-                   in ONE dispatch per round (the job is vmapped over a
+                   in ONE dispatch per round (the job is mapped over a
                    ``gen_ids`` axis).
 
 Adaptive early stopping (DESIGN.md §3-§4): ``policy="adaptive"`` orders
 rounds by discrimination/cost and ``stop_on_verdict=True`` auto-cancels
 work for a generator the moment the sequential verdict engine declares
 it definitively failed — in a multi-generator fan-out the failed
-generator drops out of the vmapped ``gen_ids`` axis on subsequent
+generator drops out of the mapped ``gen_ids`` axis on subsequent
 rounds, and once every generator is decided the remaining plan is never
 dispatched.
 
@@ -65,7 +65,8 @@ from repro.core.policies import (RetryBudgetExhausted, RetryPolicy,
                                  SchedulePolicy, get_policy)
 from repro.core.pool import (gather_captured_bits, inject_round_faults,
                              make_external_runner, make_fanout_runner,
-                             make_grid_runner, make_round_runner)
+                             make_grid_runner, make_round_runner,
+                             split_offsets)
 from repro.core.scheduler import make_plan, replan
 from repro.rng.sources import (BitSource, registry_size,
                                require_offsetable, resolve_source)
@@ -933,7 +934,7 @@ class PoolSession:
                 captured: bool = False):
         """The jitted round program for this spec's shape: the current
         pool width x G generators. ``n_gens`` overrides the spec's width —
-        adaptive runs shrink the vmapped gen_ids axis as failed generators
+        adaptive runs shrink the mapped gen_ids axis as failed generators
         drop out — and each (width, G) pair is its own cached executable,
         so resizing back to a width seen before recompiles nothing.
         Specs carrying ``offsets`` compile the grid runner (the offset is
@@ -1358,7 +1359,7 @@ class BatteryRun:
     def _dispatch(self, row: np.ndarray) -> None:
         """One round's dispatches covering the ACTIVE generators. When
         early stopping has decided some of a fan-out's generators, the
-        dispatch shrinks to the survivors — the vmapped gen_ids axis
+        dispatch shrinks to the survivors — the mapped gen_ids axis
         narrows, the failed generator's remaining tests are never
         executed. Positions backed by a ``CapturedSource`` dispatch
         through the prefetched-buffer program (their bits are gathered
@@ -1379,8 +1380,8 @@ class BatteryRun:
                                    np.int32)
                 gids = np.asarray([srcs[g].gen_id for g in switched],
                                   np.int32)
-                offs = np.asarray([self.spec.offsets[g] for g in switched],
-                                  np.int64)
+                offs = split_offsets([self.spec.offsets[g]
+                                      for g in switched])
                 stats, ps = runner(row, seeds, gids, offs)
                 stats, ps = np.asarray(stats), np.asarray(ps)
                 per_gen += [(g, stats[a], ps[a])

@@ -61,10 +61,33 @@ class TestEntry:
     n_parts: int = 1            # group size (1 = not decomposed)
     backend: str = "reference"  # kernel backend the callable is bound to
     #                             (stats/backends.py registry)
+    fallback: str = ""          # why the callable is the reference although
+    #                             backend is "accelerated" ("" = it is not)
 
     def __post_init__(self):
         if self.group < 0:
             object.__setattr__(self, "group", self.index)
+
+    @property
+    def impl(self) -> str:
+        """The implementation the callable really runs."""
+        return "reference" if self.fallback else self.backend
+
+
+def routing_report(entries: List[TestEntry]) -> List[str]:
+    """One line per kernel family: how many of its entries run each
+    implementation, and the reasons for any reference fallback under
+    the accelerated backend."""
+    fams: dict = {}
+    for e in entries:
+        f = fams.setdefault(e.kname or e.name, {"n": {}, "why": set()})
+        f["n"][e.impl] = f["n"].get(e.impl, 0) + 1
+        if e.fallback:
+            f["why"].add(e.fallback)
+    return [f"{k}: " + ", ".join(f"{n} {impl}"
+                                 for impl, n in sorted(f["n"].items()))
+            + (f" ({'; '.join(sorted(f['why']))})" if f["why"] else "")
+            for k, f in fams.items()]
 
 
 _WORDS = {
@@ -83,14 +106,14 @@ _WORDS = {
 
 
 def _mk(index, kname, scale, backend="reference", **kw):
-    fn = B.get_kernel(kname, backend)
+    fn, fallback = B.route(kname, backend, **kw)
     words = _WORDS[kname](kw)
     name = kname + ("" if not kw else "_" + "_".join(
         f"{a}{v}" for a, v in sorted(kw.items())))
     return TestEntry(index, name, functools.partial(fn, **kw), words,
                      words * KERNEL_WEIGHT[kname] * scale,
                      kname=kname, params=tuple(sorted(kw.items())),
-                     backend=backend)
+                     backend=backend, fallback=fallback)
 
 
 _BASE = [  # SmallCrush: one instance of each kernel (explicit params so
@@ -216,17 +239,23 @@ def split_entry(entry: TestEntry, n_parts: int,
     (see ``pool.stream_table``). The stitcher later folds the group's
     sub-p-values back into one verdict (Stouffer/Fisher combine).
 
-    If the re-parameterization cannot actually shrink the test (parameter
-    floors), the entry is returned unsplit — a sub-job as heavy as the
-    original mitigates nothing.
+    Parameter floors can keep a part from shrinking to 1/n_parts. The
+    split then uses the most parts (at most ``n_parts``) that together
+    read no more words than the test, and returns the entry unsplit when
+    even two parts would: a split that adds work mitigates nothing.
     """
-    if n_parts <= 1 or not entry.kname:
+    if not entry.kname:
         return [dataclasses.replace(entry, index=start_index)]
-    sub_kw = _scaled(dict(entry.params), entry.kname, 1.0 / n_parts)
-    sub_words = _WORDS[entry.kname](sub_kw)
-    if sub_words >= entry.n_words:                  # floors won: no shrink
+    while n_parts > 1:
+        sub_kw = _scaled(dict(entry.params), entry.kname, 1.0 / n_parts)
+        sub_words = _WORDS[entry.kname](sub_kw)
+        if sub_words * n_parts <= entry.n_words:
+            break
+        n_parts -= 1                                # floors bind: fewer parts
+    if n_parts <= 1:
         return [dataclasses.replace(entry, index=start_index)]
-    fn = B.get_kernel(entry.kname, entry.backend or "reference")
+    fn, fallback = B.route(entry.kname, entry.backend or "reference",
+                           **sub_kw)
     sub_cost = entry.cost * (sub_words / max(entry.n_words, 1))
     return [
         TestEntry(start_index + p,
@@ -234,6 +263,6 @@ def split_entry(entry: TestEntry, n_parts: int,
                   functools.partial(fn, **sub_kw), sub_words, sub_cost,
                   kname=entry.kname, params=tuple(sorted(sub_kw.items())),
                   group=entry.group, part=p, n_parts=n_parts,
-                  backend=entry.backend)
+                  backend=entry.backend, fallback=fallback)
         for p in range(n_parts)
     ]

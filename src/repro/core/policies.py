@@ -147,15 +147,20 @@ class RoundRobinPolicy:
 
 @dataclasses.dataclass(frozen=True)
 class LPTPolicy:
-    """Longest-processing-time-first: strictly better makespan than
-    round-robin whenever test costs are skewed (TestU01's are)."""
+    """Longest-processing-time-first: never a worse makespan than
+    round-robin, and better whenever test costs are skewed (TestU01's
+    are)."""
     name: str = "lpt"
 
     def plan(self, costs, n_workers):
-        """Greedy LPT onto the least-loaded worker."""
+        """Fill rounds in descending cost order. Rounds run in lock step,
+        so the makespan is the sum of round maxima; the r-th largest
+        round maximum of any placement is at least the ((r-1)W+1)-th
+        largest cost, which this fill attains, so it is minimal."""
         costs = np.asarray(costs, np.float64)
-        return _finish_plan(_lpt_plan(costs, n_workers), costs, n_workers,
-                            self.name)
+        return _finish_plan(_ordered_assignment(np.argsort(-costs),
+                                                n_workers),
+                            costs, n_workers, self.name)
 
     def decompose(self, entries, n_workers):
         """Never decomposes."""

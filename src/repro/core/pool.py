@@ -18,7 +18,7 @@ which is what ``PoolSession``'s compile cache exploits):
                           paper's "submit a batch, wait for output files");
                           the host driver in ``core/api.py`` loops rounds so
                           progress is checkpointable between batches.
-  ``make_fanout_runner``  the same round vmapped over a ``gen_ids`` axis —
+  ``make_fanout_runner``  the same round mapped over a ``gen_ids`` axis —
                           G generators assessed in ONE dispatch (multi-
                           generator batteries, Wartel & Hill-style).
   ``make_grid_runner``    the fan-out with a per-lane runtime stream
@@ -40,9 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map, under_x64
+from repro.common.compat import x64
 from repro.core.battery import TestEntry
-from repro.rng.generators import x64
 from repro.rng.sources import switch_block
 
 
@@ -111,7 +110,9 @@ def _job_fn(entries: List[TestEntry], with_offset: bool = False,
     """(job_id, seed, gen_id[, offset]) -> (stat, p). job_id == -1 -> idle.
 
     ``with_offset=True`` adds a runtime stream-offset argument routed to
-    the generator switch (campaign grids, ``make_grid_runner``); the
+    the generator switch (campaign grids, ``make_grid_runner``), as the
+    ``(hi, lo)`` uint32 words of ``split_offsets`` so the program's
+    arguments stay 32-bit and only generation traces in 64 bits; the
     default path traces exactly the classic three-argument job, so
     existing executables and trace counts are untouched.
 
@@ -133,8 +134,10 @@ def _job_fn(entries: List[TestEntry], with_offset: bool = False,
     so a padded round pays neither generation NOR kernel work — no
     ``n_words`` zero block is ever materialized or routed through the
     kernel switch. Both the cond predicate and the switch indices are
-    per-shard scalars, so the branches survive the fan-out vmap over
-    generators as real branches, not selects."""
+    per-shard scalars, and the fan-out runners map (``lax.map``), not
+    vmap, the job over generator lanes, so every branch stays a real
+    branch: a vmapped switch on a batched generator index would run all
+    registered generators (mwc's serial scan among them) for every lane."""
     provider = switch_block if block_provider is None else block_provider
     kernels = _kernels(entries)
     streams = jnp.asarray(stream_table(entries))
@@ -145,6 +148,9 @@ def _job_fn(entries: List[TestEntry], with_offset: bool = False,
     def gen_branch(nb):
         def gen(seed, gen_id, stream, offset=None):
             with x64():
+                if offset is not None:
+                    offset = ((offset[0].astype(jnp.uint64) << 32)
+                              | offset[1].astype(jnp.uint64))
                 block = provider(gen_id, seed, stream, nb, offset)
             if nb < n_max:
                 block = jnp.concatenate(
@@ -193,7 +199,7 @@ def make_round_runner(entries: List[TestEntry], mesh,
     job = _job_fn(entries, block_provider=block_provider)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P("workers"), P(), P()),
+        jax.shard_map, mesh=mesh, in_specs=(P("workers"), P(), P()),
         out_specs=(P("workers"), P("workers")), check_vma=False)
     def round_fn(jobs, seed, gen_id):
         if on_trace is not None:
@@ -201,51 +207,59 @@ def make_round_runner(entries: List[TestEntry], mesh,
         stat, p = job(jobs[0], seed, gen_id)
         return stat[None], p[None]
 
-    return under_x64(jax.jit(round_fn))
+    return jax.jit(round_fn)
 
 
 def make_fanout_runner(entries: List[TestEntry], mesh,
                        on_trace: Optional[Callable[[], None]] = None,
                        block_provider: Optional[Callable] = None):
     """Multi-generator round: (round_assignment (W,), seeds (G,),
-    gen_ids (G,)) -> stats, ps (G, W). The job is vmapped over the
+    gen_ids (G,)) -> stats, ps (G, W). The job is mapped over the
     generator axis, so G generators are assessed in one device dispatch."""
     job = _job_fn(entries, block_provider=block_provider)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P("workers"), P(), P()),
+        jax.shard_map, mesh=mesh, in_specs=(P("workers"), P(), P()),
         out_specs=(P(None, "workers"), P(None, "workers")), check_vma=False)
     def round_fn(jobs, seeds, gen_ids):
         if on_trace is not None:
             on_trace()
-        stat, p = jax.vmap(lambda s, g: job(jobs[0], s, g))(seeds, gen_ids)
+        stat, p = jax.lax.map(lambda a: job(jobs[0], *a), (seeds, gen_ids))
         return stat[:, None], p[:, None]
 
-    return under_x64(jax.jit(round_fn))
+    return jax.jit(round_fn)
+
+
+def split_offsets(offsets) -> np.ndarray:
+    """(G,) non-negative word offsets -> (G, 2) uint32 ``(hi, lo)`` words,
+    the grid runner's offset argument."""
+    offs = np.asarray(offsets, np.uint64)
+    return np.stack([offs >> np.uint64(32), offs & np.uint64(0xFFFFFFFF)],
+                    axis=-1).astype(np.uint32)
 
 
 def make_grid_runner(entries: List[TestEntry], mesh,
                      on_trace: Optional[Callable[[], None]] = None,
                      block_provider: Optional[Callable] = None):
     """Campaign-grid round: (round_assignment (W,), seeds (G,),
-    gen_ids (G,), offsets (G,)) -> stats, ps (G, W). Like the fan-out
-    runner but each lane of the vmapped cell axis also carries a runtime
-    stream offset, so one executable serves every (generator, sub-stream)
-    cell of a screening grid — wave after wave, knockout after knockout,
-    no retrace (DESIGN.md §8)."""
+    gen_ids (G,), offsets (G, 2) from ``split_offsets``) -> stats, ps
+    (G, W). Like the fan-out runner but each lane of the mapped cell axis
+    also carries a runtime stream offset, so one executable serves every
+    (generator, sub-stream) cell of a screening grid — wave after wave,
+    knockout after knockout, no retrace (DESIGN.md §8)."""
     job = _job_fn(entries, with_offset=True, block_provider=block_provider)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P("workers"), P(), P(), P()),
+        jax.shard_map, mesh=mesh, in_specs=(P("workers"), P(), P(), P()),
         out_specs=(P(None, "workers"), P(None, "workers")), check_vma=False)
     def round_fn(jobs, seeds, gen_ids, offsets):
         if on_trace is not None:
             on_trace()
-        stat, p = jax.vmap(lambda s, g, o: job(jobs[0], s, g, o))(
-            seeds, gen_ids, offsets)
+        stat, p = jax.lax.map(lambda a: job(jobs[0], *a),
+                              (seeds, gen_ids, offsets))
         return stat[:, None], p[:, None]
 
-    return under_x64(jax.jit(round_fn))
+    return jax.jit(round_fn)
 
 
 def _external_job_fn(entries: List[TestEntry]):
@@ -281,7 +295,7 @@ def make_external_runner(entries: List[TestEntry], mesh,
     job = _external_job_fn(entries)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("workers"), P(None, "workers", None)),
         out_specs=(P(None, "workers"), P(None, "workers")), check_vma=False)
     def round_fn(jobs, bits):
@@ -290,7 +304,7 @@ def make_external_runner(entries: List[TestEntry], mesh,
         stat, p = jax.vmap(lambda b: job(jobs[0], b))(bits[:, 0, :])
         return stat[:, None], p[:, None]
 
-    return under_x64(jax.jit(round_fn))
+    return jax.jit(round_fn)
 
 
 def gather_captured_bits(entries: List[TestEntry], jobs, lanes) -> np.ndarray:
@@ -326,7 +340,7 @@ def make_batch_runner(entries: List[TestEntry], mesh):
     job = _job_fn(entries)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(None, "workers"), P(), P()),
+        jax.shard_map, mesh=mesh, in_specs=(P(None, "workers"), P(), P()),
         out_specs=(P(None, "workers"), P(None, "workers")), check_vma=False)
     def plan_fn(jobs, seed, gen_id):
         def body(_, jid):
@@ -335,7 +349,7 @@ def make_batch_runner(entries: List[TestEntry], mesh):
         _, (stats, ps) = jax.lax.scan(body, 0, jobs)
         return stats[:, None], ps[:, None]
 
-    return under_x64(jax.jit(plan_fn))
+    return jax.jit(plan_fn)
 
 
 def inject_round_faults(injector, round_idx, row, arrays,  # repro: fault-boundary
@@ -386,7 +400,7 @@ def run_sequential(entries: List[TestEntry], seed: int, gen_id: int):
         job = _job_fn(entries)
 
         @jax.jit
-        def go(seed, gen_id):
+        def runner(seed, gen_id):
             def body(_, jid):
                 s, p = job(jid, seed, gen_id)
                 return 0, (s, p)
@@ -394,7 +408,6 @@ def run_sequential(entries: List[TestEntry], seed: int, gen_id: int):
                 body, 0, jnp.arange(len(entries), dtype=jnp.int32))
             return stats, ps
 
-        runner = under_x64(go)
         if len(_SEQ_RUNNERS) >= 32:              # bound the executable pool
             _SEQ_RUNNERS.pop(next(iter(_SEQ_RUNNERS)))
         _SEQ_RUNNERS[key] = runner

@@ -55,7 +55,7 @@ def wave_makespan(costs: Sequence[float], n_workers: int, n_cells: int,
                   mode: Union[str, SchedulePolicy] = "lpt") -> tuple:
     """``(batched, per_cell)`` estimated makespans of one campaign wave
     over ``n_cells`` grid cells. Batched is the campaign's model — one
-    plan whose round dispatches carry every cell on the vmapped cell
+    plan whose round dispatches carry every cell on the mapped cell
     axis, so the schedule is paid once; per-cell is the naive loop it
     replaces (the plan dispatched once per cell). The ratio is the
     batching win the campaign benchmark measures."""

@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map
-
 
 def quantize_int8(x):
     """Per-tensor symmetric int8. Returns (q, scale)."""
@@ -53,7 +51,7 @@ def cross_pod_mean(grads, mesh):
         return grads
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=jax.tree_util.tree_map(lambda _: P("pod"), grads),
         out_specs=jax.tree_util.tree_map(lambda _: P("pod"), grads),
         check_vma=False)

@@ -1,13 +1,16 @@
 """Pallas TPU kernel: bit-packed GF(2) matrix rank (MatrixRank test hot spot).
 
-TestU01 does word-level Gaussian elimination on CPU. TPU adaptation: a whole
-32x32 bit-matrix lives in ONE 32-lane uint32 vector register row, so a VMEM
-tile of (TILE_M, 32) holds TILE_M matrices and the 32-step elimination is a
-fully vectorized mask/XOR dance on the VPU — no MXU needed, no gather/swap
-(pivot selection via argmax over candidate masks).
+TestU01 does word-level Gaussian elimination on CPU. TPU adaptation: a
+32x32 bit-matrix is 32 int32 row words, and a VMEM tile of (32, TILE_M)
+holds TILE_M matrices with row r of every matrix on sublane row r and
+the matrices along lanes. Elimination walks the rows in order: row r
+(a static slice, already reduced by the rows above it) is a pivot iff it
+is non-zero, its pivot column is its lowest set bit ``p & -p``, and that
+bit is cleared from every later row holding it. The rank is the number
+of non-zero pivots. Every step is elementwise int32 work on the VPU —
+no reductions, no argmax and no gathers, which is what Mosaic lowers.
 
-Grid: one program per TILE_M matrices. BlockSpec keeps the (TILE_M, 32)
-tile + (TILE_M,) rank output resident in VMEM.
+Grid: one program per TILE_M matrices.
 """
 from __future__ import annotations
 
@@ -20,31 +23,16 @@ from jax.experimental import pallas as pl
 TILE_M = 256
 
 
-def _rank_kernel(mats_ref, rank_ref):
-    rows = mats_ref[...]                                   # (TILE_M, 32) u32
-    m = rows.shape[0]
-    used = jnp.zeros((m, 32), jnp.bool_)
-    rank = jnp.zeros((m,), jnp.int32)
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (m, 32), 1)
-
-    def body(i, st):
-        rows, used, rank = st
-        col = ((rows >> (31 - i).astype(jnp.uint32)) & 1) == 1
-        cand = col & ~used
-        has = cand.any(axis=1)
-        piv = jnp.argmax(cand, axis=1)                     # first candidate
-        # dtype pinned: under an ambient-x64 trace (the battery runners)
-        # jnp.sum would promote uint32 -> uint64 and break the carry
-        pivrow = jnp.sum(jnp.where(ridx == piv[:, None], rows, 0), axis=1,
-                         dtype=jnp.uint32)
-        pivrow = jnp.where(has, pivrow, 0)
-        apply = col & (ridx != piv[:, None])
-        rows = jnp.where(apply, rows ^ pivrow[:, None], rows)
-        used = used | ((ridx == piv[:, None]) & has[:, None])
-        rank = rank + has.astype(jnp.int32)
-        return rows, used, rank
-
-    _, _, rank = jax.lax.fori_loop(0, 32, body, (rows, used, rank))
+def _rank_kernel(rows_ref, rank_ref):
+    rows = rows_ref[...]                                   # (32, TILE_M) i32
+    later = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+    rank = jnp.zeros((1, rows.shape[1]), jnp.int32)
+    for r in range(32):
+        p = rows[r:r + 1, :]
+        low = p & (-p)                          # pivot column bit (0 if p==0)
+        hit = ((rows & low) != 0) & (later > r)
+        rows = jnp.where(hit, rows ^ p, rows)
+        rank = rank + (p != 0).astype(jnp.int32)
     rank_ref[...] = rank
 
 
@@ -57,12 +45,12 @@ def gf2_rank(mats: jax.Array, interpret: bool = True) -> jax.Array:
     """
     m = mats.shape[0]
     assert m % TILE_M == 0, m
-    grid = (m // TILE_M,)
+    rows = jax.lax.bitcast_convert_type(mats, jnp.int32).T     # (32, M)
     return pl.pallas_call(
         _rank_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((TILE_M, 32), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((TILE_M,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
+        grid=(m // TILE_M,),
+        in_specs=[pl.BlockSpec((32, TILE_M), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, TILE_M), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
         interpret=interpret,
-    )(mats)
+    )(rows)[0]

@@ -4,12 +4,13 @@
       --battery bigcrush --gen splitmix64 --workers 8 --scale 0.05
 
 ``--gen`` takes a comma-separated list: several generators are assessed in
-ONE dispatch per round (the pool vmaps the job over a gen_ids axis).
-Set ``--workers N`` (>1) to fork the pool onto N forced host devices (the
-dry-run trick, battery-sized); on a real TPU pod the same code runs on the
-flattened device mesh. Checkpoints progress per round; re-running the same
-command resumes (only missing tests execute). ``--json PATH`` writes a
-machine-readable report next to the text one.
+ONE dispatch per round (the pool maps the job over a gen_ids axis).
+``--workers N`` spreads the pool over N devices: N chips on an
+accelerator host, N CPU devices elsewhere. Compiled programs persist in
+JAX's compilation cache (``JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``). Checkpoints progress per round; re-running
+the same command resumes (only missing tests execute). ``--json PATH``
+writes a machine-readable report next to the text one.
 
 ``--source file:PATH[:fmt]`` screens a CAPTURED bitstream (a file of
 raw uint32 words, ``fmt`` ``npy`` or ``u32``) through the same battery
@@ -208,15 +209,16 @@ def main():
         if any(w < 1 for w in resize_at.values()):
             ap.error("--resize-at widths must be >= 1")
 
-    # the forced host-device pool must cover the widest point of the run
-    need = max([args.workers] + list(resize_at.values()))
-    if need > 1 and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={need}"
+    # on a host without accelerators the CPU pool must cover the widest
+    # point of the run
+    from repro.common.runtime import cpu_devices, use_compile_cache
+    cpu_devices(max([args.workers] + list(resize_at.values())))
+    use_compile_cache()
 
-    from repro.core import stitch                     # noqa: E402 (after env)
+    from repro.core import stitch                     # noqa: E402
     from repro.core.api import (                      # noqa: E402
         BatteryResult, CampaignSpec, PoolSession, RunSpec)
+    from repro.core.battery import routing_report     # noqa: E402
     from repro.core.faults import FaultPlan           # noqa: E402
     from repro.core.policies import (                 # noqa: E402
         RetryBudgetExhausted, RetryPolicy)
@@ -342,6 +344,9 @@ def main():
           f"backend={args.backend}"
           + (f"->{backend_resolved}" if args.backend == "auto" else "")
           + (f" adaptive(alpha={args.alpha})" if args.adaptive else ""))
+    if backend_resolved == "accelerated":
+        for line in routing_report(session.entries(spec)):
+            print(f"  kernels {line}")
 
     resizes = []
     serve_info = None

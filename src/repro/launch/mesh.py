@@ -17,7 +17,20 @@ import numpy as np
 
 import jax
 
-from repro.common.compat import make_mesh
+
+def _mesh(shape, axes, devices):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def _too_few(what: str, n: int, have) -> str:
+    """The error text for a mesh wider than the visible devices."""
+    platform = have[0].platform if have else "none"
+    return (f"{what} needs {n} devices, have {len(have)} ({platform}); "
+            f"on accelerators run on a host with >= {n} chips "
+            f"(chip_smoke.py --chips 4 wants a four-chip host); on the "
+            f"CPU set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
+            f"before importing jax")
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,11 +39,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, have {len(devices)}; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            "before importing jax (dry-run only)")
-    return make_mesh(shape, axes, devices=devices[:n])
+        raise RuntimeError(_too_few(f"mesh {shape}", n, devices))
+    return _mesh(shape, axes, devices[:n])
 
 
 def make_pool_mesh(n_workers: int | None = None):
@@ -44,8 +54,5 @@ def make_pool_mesh(n_workers: int | None = None):
     if n < 1:
         raise ValueError(f"pool width must be >= 1, got {n}")
     if n > len(devices):
-        raise RuntimeError(
-            f"pool of {n} workers needs {n} devices, have {len(devices)}; "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            "before importing jax (dry-run only)")
-    return make_mesh((n,), ("workers",), devices=devices[:n])
+        raise RuntimeError(_too_few(f"pool of {n} workers", n, devices))
+    return _mesh((n,), ("workers",), devices[:n])

@@ -117,9 +117,9 @@ def main():
         ap.error(f"--submit {args.submit}: expected a non-empty JSON list "
                  "of submission dicts")
 
-    if args.workers > 1 and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={args.workers}"
+    from repro.common.runtime import cpu_devices, use_compile_cache
+    cpu_devices(args.workers)
+    use_compile_cache()
 
     from repro.core.api import PoolSession            # noqa: E402 (after env)
     from repro.launch.mesh import make_pool_mesh      # noqa: E402

@@ -19,10 +19,11 @@ benchmarks). Only MWC still runs as ``lax.scan``: its lag-1 carry chain
 has no cheap jump. RANDU is deliberately included as a known-bad
 generator the battery must flag.
 
-64-bit integer ops require tracing under x64 (``with x64():`` —
-``jax.experimental.enable_x64``); constants here are Python ints so nothing
-truncates at import time. All public entry points are safe to trace inside
-the battery's jitted programs.
+64-bit integer ops require tracing under x64 (``with x64():``, the one
+scoped switch in ``common/compat.py``); constants here are Python ints so
+nothing truncates at import time. All public entry points are safe to
+trace inside the battery's jitted programs, which open the scope around
+generation only.
 """
 from __future__ import annotations
 
@@ -35,11 +36,6 @@ import numpy as np
 
 GOLDEN = 0x9E3779B97F4A7C15
 MASK32 = 0xFFFFFFFF
-
-
-def x64():
-    """Context manager enabling 64-bit tracing (jax.experimental.enable_x64)."""
-    return jax.experimental.enable_x64()
 
 
 def _u64(x):
@@ -107,25 +103,27 @@ LCG_A = 6364136223846793005
 LCG_C = 1442695040888963407
 
 
-def _lcg_jump(s0, idx):
-    """state_i = A^i s0 + C (A^i-1)/(A-1), per lane in O(64) steps."""
-    a_acc = jnp.ones_like(idx)
-    c_acc = jnp.zeros_like(idx)
-    a_pow = jnp.broadcast_to(_u64(LCG_A), idx.shape)
-    c_pow = jnp.broadcast_to(_u64(LCG_C), idx.shape)
-    for bit in range(64):
-        take = ((idx >> bit) & 1) == 1
+def _lcg_jump(s0, idx, nbits=64):
+    """state_i = A^i s0 + C (A^i-1)/(A-1), per lane in ``nbits`` ladder
+    steps (enough to cover every lane index, see ``_jump_bits``)."""
+    def step(bit, st):
+        a_acc, c_acc, a_pow, c_pow = st
+        take = ((idx >> bit.astype(jnp.uint64)) & 1) == 1
         c_acc = jnp.where(take, c_acc * a_pow + c_pow, c_acc)
         a_acc = jnp.where(take, a_acc * a_pow, a_acc)
-        c_pow = c_pow * (a_pow + 1)
-        a_pow = a_pow * a_pow
+        return a_acc, c_acc, a_pow * a_pow, c_pow * (a_pow + 1)
+    init = (jnp.ones_like(idx), jnp.zeros_like(idx),
+            jnp.broadcast_to(_u64(LCG_A), idx.shape),
+            jnp.broadcast_to(_u64(LCG_C), idx.shape))
+    a_acc, c_acc, _, _ = jax.lax.fori_loop(0, nbits, step, init)
     return a_acc * s0 + c_acc
 
 
 def pcg32_block(seed, stream, n, offset=0):
     """PCG-XSH-RR 64/32 with per-lane LCG jump-ahead."""
     st = _lcg_jump(_mix_seed(seed, stream),
-                   jnp.arange(n, dtype=jnp.uint64) + _u64(offset))
+                   jnp.arange(n, dtype=jnp.uint64) + _u64(offset),
+                   _jump_bits(n, offset))
     xorshifted = (((st >> 18) ^ st) >> 27).astype(jnp.uint32)
     rot = (st >> 59).astype(jnp.uint32)
     return (xorshifted >> rot) | (xorshifted << ((-rot) & jnp.uint32(31)))
@@ -133,7 +131,8 @@ def pcg32_block(seed, stream, n, offset=0):
 
 def lcg64_block(seed, stream, n, offset=0):
     st = _lcg_jump(_mix_seed(seed, stream),
-                   jnp.arange(n, dtype=jnp.uint64) + _u64(offset))
+                   jnp.arange(n, dtype=jnp.uint64) + _u64(offset),
+                   _jump_bits(n, offset))
     return _hi32(st)
 
 
@@ -142,8 +141,10 @@ def lcg64_block(seed, stream, n, offset=0):
 
 def _jump_bits(n, offset):
     """Ladder length: enough exponent bits to cover every lane index
-    ``1..n+offset``. Static when offset is a Python int (the battery hot
-    path); a traced offset falls back to the full 64-bit ladder."""
+    ``0..n+offset``. Static when offset is a Python int (the battery hot
+    path); a traced offset falls back to the full 64-bit ladder. The
+    ladders are rolled loops (``lax.fori_loop``), so their program size
+    does not grow with the length."""
     if isinstance(offset, (int, np.integer)):
         return max(int(int(n) + int(offset)).bit_length(), 1)
     return 64
@@ -152,12 +153,13 @@ def _jump_bits(n, offset):
 def _pow_jump(idx, mult, nbits, mulmod):
     """``mult^idx`` per lane by square-and-multiply — the ``_lcg_jump``
     ladder generalized to any associative product ``mulmod``."""
-    acc = jnp.ones_like(idx)
-    apow = jnp.broadcast_to(_u64(mult), idx.shape)
-    for bit in range(nbits):
-        take = ((idx >> bit) & 1) == 1
-        acc = jnp.where(take, mulmod(acc, apow), acc)
-        apow = mulmod(apow, apow)
+    def step(bit, st):
+        acc, apow = st
+        take = ((idx >> bit.astype(jnp.uint64)) & 1) == 1
+        return jnp.where(take, mulmod(acc, apow), acc), mulmod(apow, apow)
+    acc, _ = jax.lax.fori_loop(
+        0, nbits, step,
+        (jnp.ones_like(idx), jnp.broadcast_to(_u64(mult), idx.shape)))
     return acc
 
 
@@ -193,17 +195,17 @@ def _xs_jump(s0, idx, nbits):
     scan. The matvec is an XOR-reduce of the state-selected columns
     (one (lanes, 64) reduce per ladder step keeps the trace small; XOR
     is exact, so bit-exactness vs the scan twin is preserved)."""
-    pows = _xs_jump_cols()
-    s = jnp.broadcast_to(s0, idx.shape)
+    pows = jnp.asarray(_xs_jump_cols())
     bitpos = jnp.arange(64, dtype=jnp.uint64)
-    for k in range(nbits):
-        take = ((idx >> k) & 1) == 1
-        cols = jnp.asarray(pows[k])
+
+    def step(k, s):
+        take = ((idx >> k.astype(jnp.uint64)) & 1) == 1
         sel = jnp.where(((s[:, None] >> bitpos[None, :]) & 1) == 1,
-                        cols[None, :], _u64(0))
+                        pows[k][None, :], _u64(0))
         y = jax.lax.reduce(sel, _u64(0), jax.lax.bitwise_xor, (1,))
-        s = jnp.where(take, y, s)
-    return s
+        return jnp.where(take, y, s)
+    return jax.lax.fori_loop(0, nbits, step,
+                             jnp.broadcast_to(s0, idx.shape))
 
 
 # xorshift cycle-split chunk: each lane jump-starts its segment with the
@@ -253,14 +255,43 @@ def randu_block(seed, stream, n, offset=0):
     return (st << 1).astype(jnp.uint32)
 
 
+M31 = 2147483647                # 2^31 - 1, MINSTD's (Mersenne) modulus
+
+
+def _mod_m31(x):
+    """``x mod (2^31 - 1)`` for uint64 ``x`` by Mersenne folding:
+    2^31 = 1 (mod 2^31 - 1), so adding the 31-bit digits keeps the
+    residue. Shifts and adds only — a 64-bit remainder is a long
+    emulated sequence on accelerators without 64-bit integer units."""
+    m = _u64(M31)
+    x = (x & m) + ((x >> 31) & m) + (x >> 62)       # < 2^32 + 2
+    x = (x & m) + (x >> 31)                         # <= 2^31 + 1
+    return jnp.where(x >= m, x - m, x)
+
+
+def _minstd_seed(seed, stream):
+    """``mix mod (2^31 - 2) + 1``, a state in [1, 2^31 - 2]. The modulus
+    is 2 (2^30 - 1): the residue mod the Mersenne number 2^30 - 1 comes
+    from folding 30-bit digits, and of its two lifts below 2 (2^30 - 1)
+    the one with the parity of ``mix`` is the residue (Chinese
+    remainders; 2^30 - 1 is odd)."""
+    x = _mix_seed(seed, stream)
+    m = _u64((1 << 30) - 1)
+    q = (x & m) + ((x >> 30) & m) + (x >> 60)       # < 2^32
+    q = (q & m) + (q >> 30)                         # < 2^30 + 4
+    q = jnp.where(q >= m, q - m, q)
+    q = jnp.where((q & _u64(1)) == (x & _u64(1)), q, q + m)
+    return q + _u64(1)
+
+
 def minstd_block(seed, stream, n, offset=0):
     """MINSTD: x <- 16807 x mod (2^31 - 1), via multiplicative jump-ahead
     (prime modulus; 62-bit products fit uint64)."""
-    s0 = (_mix_seed(seed, stream) % _u64(2147483646)) + _u64(1)
+    s0 = _minstd_seed(seed, stream)
     idx = jnp.arange(1, n + 1, dtype=jnp.uint64) + _u64(offset)
 
     def mm(a, b):
-        return (a * b) % _u64(2147483647)
+        return _mod_m31(a * b)
     st = mm(jnp.broadcast_to(s0, idx.shape),
             _pow_jump(idx, 16807, _jump_bits(n, offset), mm))
     return (st << 1).astype(jnp.uint32)

@@ -44,6 +44,8 @@ from typing import Callable, Dict, Optional, Tuple, Union
 import jax
 import numpy as np
 
+from repro.common.compat import x64
+
 
 class OffsetNotSupportedError(ValueError):
     """A non-zero stream offset was requested from a source that cannot
@@ -422,7 +424,7 @@ def capture_generator(name: str, path: str, seed: int, n_streams: int,
     if n_streams < 1 or stride < 1:
         raise ValueError(f"need n_streams >= 1 and stride >= 1, got "
                          f"{n_streams}, {stride}")
-    with jax.experimental.enable_x64():
+    with x64():
         shards = [np.asarray(row.block_fn(seed, s, stride), np.uint32)
                   for s in range(n_streams)]
     words = np.stack(shards)

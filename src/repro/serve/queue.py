@@ -14,7 +14,7 @@ many-client screening workload cheap (DESIGN.md §10):
                        (battery, scale, alpha, backend, policy,
                        stop_on_verdict) are coalesced into ONE merged
                        multi-generator spec — strangers share a round on
-                       the vmapped gen_ids axis, results are demuxed
+                       the mapped gen_ids axis, results are demuxed
                        back per ticket (``stitch.demux_positions``). A
                        ``max_wait`` bound keeps admission fair: a lone
                        submission is admitted once it has waited that

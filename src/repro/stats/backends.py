@@ -3,35 +3,44 @@
 ``stats/tests.py``) and — where a hand-written Pallas kernel covers the
 hot loop — an ``accelerated`` implementation (DESIGN.md §7).
 
-The accelerated paths route the counting hot loops through the fused
-Pallas kernels that previously sat unused:
+The accelerated paths route the counting hot loops through the Pallas
+kernels:
 
   gap / poker / weight / serial2d / collision
-      -> ``kernels/histogram`` (scatter-free fused bin-count; collision
-         only below ``HIST_MAX_BINS`` urns — paper-sized collision
-         entries keep the sort-based path, see ``collision_accel``)
+      -> ``kernels/histogram`` (scatter-free bin-count, bin axis tiled)
   rank
       -> ``kernels/gf2_rank``  (bit-packed GF(2) elimination)
 
-Families whose hot loop has no Pallas kernel (birthday, coupon, maxoft,
-hamcorr) fall back to the reference implementation under the
-``accelerated`` backend, so a battery-wide backend choice always
-resolves. Both implementations of a family share the same probability
-model and p-value machinery — parity to float32 tolerance is asserted in
-``tests/test_backends.py`` for every registered family.
+``route`` decides, per battery entry, which implementation runs and
+says why when it is not the one asked for. Under ``accelerated`` an
+entry runs the reference when
+
+  * its family has no Pallas kernel (birthday, coupon, maxoft, hamcorr,
+    pairstream), or
+  * its histogram would have more than ``HIST_MAX_BINS`` bins: the
+    bin-count kernel compares every index with every bin, so its work
+    grows as N x K. Collision entries at paper scale (2^24..2^28 urns)
+    keep the sort-based reference for that reason.
+
+The reason is recorded on the ``TestEntry`` (``fallback``) and printed
+by the launchers — a fallback is never silent. Both implementations of
+a family share the same probability model and p-value machinery;
+parity to float32 tolerance is asserted in ``tests/test_backends.py``
+for every registered family.
 
 Backend names:
 
-  ``reference``    today's pure-jnp kernels — the oracle
-  ``accelerated``  Pallas kernels (``interpret="auto"``: compiled on real
-                   TPU, interpreted on CPU so CI exercises the same code)
+  ``reference``    the pure-jnp kernels — the oracle
+  ``accelerated``  Pallas kernels (``interpret="auto"``: compiled on
+                   TPU, interpreted elsewhere so CI runs the same code)
   ``auto``         resolves to ``accelerated`` on a TPU backend and
                    ``reference`` everywhere else
 """
 from __future__ import annotations
 
+import inspect
 import math
-from typing import Callable, Dict
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,12 +55,16 @@ from repro.stats.special import (chi2_from_counts, chi2_sf,
 
 BACKENDS = ("auto", "reference", "accelerated")
 
-# Densest urn space the fused bin-count will materialize: the histogram
-# kernel compares a (CHUNK, K) tile per grid step, so K is VMEM-bounded.
-# Collision jobs with more urns than this keep the sort-based reference
-# path even under the accelerated backend (static Python branch — kbits
-# is a battery parameter, not a traced value).
+# Most bins ``route`` sends to the bin-count kernel. VMEM does not bound
+# K (the kernel tiles the bin axis); its N x K compare work does.
 HIST_MAX_BINS = 1 << 16
+
+# bins of the histogram an accelerated entry builds, by its params (a
+# param the entry leaves unset takes the accelerated function's default)
+_HIST_BINS = {
+    "collision": lambda p: 1 << p["kbits"],
+    "serial2d": lambda p: p["d"] ** 2,
+}
 
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 
@@ -89,15 +102,27 @@ def resolve(backend: str) -> str:
     return default_backend() if backend == "auto" else backend
 
 
-def get_kernel(kname: str, backend: str = "reference") -> Callable:
-    """The family's implementation under ``backend`` (resolved). A family
-    without an accelerated implementation falls back to its reference —
-    a battery-wide backend choice must always produce a full job table."""
+def route(kname: str, backend: str = "reference",
+          **params) -> Tuple[Callable, str]:
+    """``(fn, fallback)``: the implementation an entry of family ``kname``
+    with ``params`` runs under ``backend`` (resolved), and why it is the
+    reference although ``accelerated`` was asked for ("" when it is what
+    was asked). A battery-wide backend choice always yields a full job
+    table; the fallback reason is what makes the choice visible."""
     impls = _REGISTRY[kname]
-    b = resolve(backend)
-    if b not in impls:
-        b = "reference"
-    return impls[b]
+    if resolve(backend) == "reference":
+        return impls["reference"], ""
+    if "accelerated" not in impls:
+        return impls["reference"], f"{kname} has no accelerated kernel"
+    fn = impls["accelerated"]
+    if kname in _HIST_BINS:
+        defaults = {k: v.default for k, v in
+                    inspect.signature(fn).parameters.items()}
+        bins = _HIST_BINS[kname]({**defaults, **params})
+        if bins > HIST_MAX_BINS:
+            return impls["reference"], (f"{bins} bins > HIST_MAX_BINS="
+                                        f"{HIST_MAX_BINS}")
+    return fn, ""
 
 
 # ---------------------------------------------------------------------------
@@ -166,18 +191,17 @@ def serial2d_accel(bits, n=65536, d=64):
     y = (u[1::2] >> (32 - dbits)).astype(jnp.int32)
     cell = (x * d + y).astype(jnp.int32)
     counts = bincount(cell, d * d)
-    stat = chi2_from_counts(counts, jnp.full((d * d,), n / (d * d)))
+    stat = chi2_from_counts(counts, np.full((d * d,), n / (d * d),
+                                            np.float32))
     return stat, chi2_sf(stat, d * d - 1)
 
 
 def collision_accel(bits, n=65536, kbits=24):
     """`collision` with urn occupancy on the fused bin-count: distinct
-    urns = occupied bins, so the collision count needs no sort. Falls
-    back to the sort-based reference when the urn space exceeds
-    ``HIST_MAX_BINS`` (dense occupancy would not fit VMEM)."""
+    urns = occupied bins, so the collision count needs no sort. ``route``
+    sends urn spaces above ``HIST_MAX_BINS`` to the reference."""
     k = 1 << kbits
-    if k > HIST_MAX_BINS:
-        return T.collision(bits, n=n, kbits=kbits)
+    assert k <= HIST_MAX_BINS, (k, HIST_MAX_BINS)
     urns = (bits[:n] >> (32 - kbits)).astype(jnp.int32)
     occ = bincount(urns, k)
     distinct = jnp.sum(occ > 0).astype(jnp.float32)

@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def chi2_sf(x, k):
@@ -52,7 +53,22 @@ def ks_pvalue(sorted_u):
 
 
 def chi2_from_counts(counts, expected):
-    """(stat, dof) with TestU01-style clamping of tiny expected bins."""
+    """Pearson chi-square statistic, with TestU01-style clamping of tiny
+    expected bins.
+
+    ``expected`` is either a numpy array (counts known on the host) or a
+    traced array (counts that depend on the data, as in gap and coupon,
+    which scale by the observed number of hits or segments). A numpy
+    array is inverted on the host, and each compiled program multiplies
+    by the same reciprocal: a device division by a constant is rewritten
+    by XLA into a reciprocal multiply in some programs and not in others
+    (a constant hoisted out of a branch stays a division), which made
+    the statistic depend on the pool width in its last bit. A traced
+    divisor is no constant, so no program rewrites it, and it is divided
+    on the device."""
+    if isinstance(expected, np.ndarray):
+        expected = expected.clip(min=1e-9).astype(np.float32)
+        inv = (1.0 / expected).astype(np.float32)
+        return jnp.sum(jnp.square(counts - expected) * inv)
     expected = jnp.maximum(expected, 1e-9)
-    stat = jnp.sum(jnp.square(counts - expected) / expected)
-    return stat
+    return jnp.sum(jnp.square(counts - expected) / expected)

@@ -235,7 +235,8 @@ def serial2d(bits, n=65536, d=64):
     y = (u[1::2] >> (32 - dbits)).astype(jnp.int32)
     cell = x * d + y
     counts = jnp.bincount(cell, length=d * d).astype(jnp.float32)
-    stat = chi2_from_counts(counts, jnp.full((d * d,), n / (d * d)))
+    stat = chi2_from_counts(counts, np.full((d * d,), n / (d * d),
+                                            np.float32))
     return stat, chi2_sf(stat, d * d - 1)
 
 

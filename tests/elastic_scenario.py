@@ -61,7 +61,13 @@ out["single_bitwise"] = (keyed(fixed.submit(spec1).result())
 out["single_trace_widths"] = sorted(
     [k[2], v] for k, v in elastic.trace_counts.items())
 
-# --- 2. multi-generator fan-out (vmapped gen_ids axis) across a resize
+# --- 1b. a one-worker pool (a program compiled without partitioning)
+# stitches the same bits as the 8-wide one
+out["width1_bitwise"] = (keyed(fixed.submit(spec1).result())
+                         == keyed(PoolSession(n_workers=1).submit(spec1)
+                                  .result()))
+
+# --- 2. multi-generator fan-out (mapped gen_ids axis) across a resize
 spec2 = RunSpec("smallcrush", ("splitmix64", "randu"), 7, scale=SCALE)
 out["fanout_bitwise"] = (keyed(fixed.submit(spec2).result())
                          == keyed(drive_resized(elastic, spec2)))

@@ -47,9 +47,9 @@ def spec_for(plan=None, retry=None, policy="lpt"):
 
 clean = session.submit(spec_for()).result()
 # roundrobin keeps every slot busy on consecutive rounds, so a
-# persistently flaky slot actually accumulates the quarantine streak
-# (LPT idles narrow slots late in the battery); parity must hold across
-# policies anyway, but the baseline matches the policy under test
+# persistently flaky slot actually accumulates the quarantine streak;
+# parity must hold across policies anyway, but the baseline matches the
+# policy under test
 clean_rr = session.submit(spec_for(policy="roundrobin")).result()
 assert clean_rr.results == clean.results
 

@@ -185,6 +185,17 @@ def test_split_entry_shrinks_and_groups():
     assert len(split_entry(tiny, 4, start_index=0)) == 1
 
 
+def test_split_entry_floors_never_add_words():
+    # crush at 1/4 scale: rank over 512 matrices; a quarter would be 128,
+    # under the 256-matrix floor, so four parts would read twice the words
+    rank = next(e for e in build_battery("crush", 0.25)
+                if e.name == "rank_n_mats512")
+    subs = split_entry(rank, 4, start_index=0)
+    assert len(subs) == 2
+    assert sum(s.n_words for s in subs) <= rank.n_words
+    assert all(s.n_parts == 2 for s in subs)
+
+
 def test_decompose_covers_all_tests_with_unique_streams():
     entries = build_battery("smallcrush", SCALE)
     jobs = OverDecomposePolicy(threshold=0.05, max_parts=4).decompose(

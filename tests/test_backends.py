@@ -8,9 +8,10 @@ must stitch the same verdict under ``backend=reference`` and
 import numpy as np
 import pytest
 
+from repro.common.compat import x64
 from repro.core import pool
 from repro.core.api import PoolSession, RunSpec
-from repro.core.battery import build_battery, split_entry
+from repro.core.battery import build_battery, routing_report, split_entry
 from repro.rng import generators as G
 from repro.stats import backends as B
 
@@ -33,7 +34,7 @@ PARITY_CASES = {
 
 
 def _bits(seed, n=262144):
-    with G.x64():
+    with x64():
         return G.splitmix64_block(seed, 1, n)
 
 
@@ -55,9 +56,42 @@ def test_resolve_and_auto():
 
 def test_fallback_for_unaccelerated_family():
     """A family without an accelerated impl resolves to its reference —
-    a battery-wide backend choice always yields a full job table."""
-    assert B.get_kernel("birthday", "accelerated") is B.get_kernel(
-        "birthday", "reference")
+    a battery-wide backend choice always yields a full job table — and
+    the entry says why."""
+    assert B.route("birthday", "accelerated")[0] is B.route(
+        "birthday", "reference")[0]
+    assert B.route("birthday", "accelerated")[1] == (
+        "birthday has no accelerated kernel")
+    assert B.route("birthday", "reference")[1] == ""
+
+
+def test_routing_report_names_every_fallback():
+    """The launchers' per-family routing lines: counts per implementation
+    and the reason for each reference fallback under accelerated."""
+    lines = routing_report(build_battery("bigcrush", 1.0,
+                                         backend="accelerated"))
+    by = {line.split(":")[0]: line for line in lines}
+    assert by["rank"] == "rank: 8 accelerated"
+    assert by["birthday"] == ("birthday: 12 reference "
+                              "(birthday has no accelerated kernel)")
+    assert by["collision"].startswith("collision: 13 reference (")
+    assert "HIST_MAX_BINS" in by["collision"]
+
+
+def test_resolve_interpret_never_hides_the_chip(monkeypatch):
+    """"auto" interprets exactly off a TPU; an explicit interpreter on a
+    TPU is refused outside a test run."""
+    import jax
+
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret("auto") is (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret("auto") is False
+    assert resolve_interpret(True) is True          # inside a test: allowed
+    monkeypatch.delenv("PYTEST_CURRENT_TEST")
+    with pytest.raises(ValueError, match="interpreter"):
+        resolve_interpret(True)
 
 
 # ------------------------------------------------- (stat, p) parity
@@ -65,12 +99,10 @@ def test_fallback_for_unaccelerated_family():
 @pytest.mark.parametrize("family", sorted(PARITY_CASES))
 @pytest.mark.parametrize("seed", [1, 7, 31])
 def test_accelerated_matches_reference(family, seed):
-    ref = B.get_kernel(family, "reference")
-    acc = B.get_kernel(family, "accelerated")
     bits = _bits(seed)
     for kw in PARITY_CASES[family]:
-        s1, p1 = ref(bits, **kw)
-        s2, p2 = acc(bits, **kw)
+        s1, p1 = B.route(family, "reference", **kw)[0](bits, **kw)
+        s2, p2 = B.route(family, "accelerated", **kw)[0](bits, **kw)
         np.testing.assert_allclose(float(s1), float(s2), rtol=1e-5,
                                    err_msg=f"{family} stat {kw}")
         np.testing.assert_allclose(float(p1), float(p2), rtol=1e-5,
@@ -78,13 +110,24 @@ def test_accelerated_matches_reference(family, seed):
 
 
 def test_collision_large_urn_space_falls_back():
-    """Above HIST_MAX_BINS the accelerated collision keeps the sort-based
-    path (dense occupancy would not fit VMEM) — and still agrees."""
-    bits = _bits(3)
+    """Above HIST_MAX_BINS the accelerated backend routes collision to the
+    sort-based reference (the bin-count's N x K compare work), says so,
+    and the battery entry records the reason."""
     kw = dict(n=4096, kbits=24)          # 2^24 urns > HIST_MAX_BINS
-    s1, p1 = B.get_kernel("collision", "reference")(bits, **kw)
-    s2, p2 = B.get_kernel("collision", "accelerated")(bits, **kw)
+    fn, why = B.route("collision", "accelerated", **kw)
+    assert fn is B.route("collision", "reference")[0]
+    assert "HIST_MAX_BINS" in why
+    fn, why = B.route("collision", "accelerated", n=4096, kbits=16)
+    assert fn is B.collision_accel and why == ""
+    bits = _bits(3)
+    s1, p1 = B.route("collision", "reference")[0](bits, **kw)
+    s2, p2 = B.route("collision", "accelerated", **kw)[0](bits, **kw)
     assert float(s1) == float(s2) and float(p1) == float(p2)
+    acc = build_battery("bigcrush", 1.0, backend="accelerated")
+    coll = [e for e in acc if e.kname == "collision"]
+    assert coll and all(e.impl == "reference" and e.fallback for e in coll)
+    assert all(e.impl == "accelerated" for e in acc
+               if e.kname in ("gap", "poker", "weight", "serial2d", "rank"))
 
 
 # --------------------------------------- jump-ahead generator bit-exactness
@@ -93,7 +136,7 @@ def test_collision_large_urn_space_falls_back():
 @pytest.mark.parametrize("seed", [0, 9, 123])
 def test_jump_matches_scan(gen, seed):
     jump, scan = G.GENERATORS[gen], G.SCAN_REFERENCE[gen]
-    with G.x64():
+    with x64():
         for n in (37, 1024):
             a = np.asarray(jump(seed, 5, n))
             b = np.asarray(scan(seed, 5, n))
@@ -106,7 +149,7 @@ def test_jump_offset_continuation(gen):
     the property that lets the former scan generators join
     COUNTER_BASED."""
     jump = G.GENERATORS[gen]
-    with G.x64():
+    with x64():
         full = np.asarray(jump(11, 2, 300))
         for k in (1, 128, 299):
             tail = np.asarray(jump(11, 2, 300 - k, offset=k))

@@ -4,6 +4,7 @@ batched-dispatch trace accounting, and ledger resume."""
 import numpy as np
 import pytest
 
+from repro.common.compat import x64
 from repro.core import stitch
 from repro.core.api import (CELL_FAIL, CELL_PASS, CELL_UNDECIDED,
                             CampaignLedger, CampaignSpec, PoolSession,
@@ -79,7 +80,7 @@ def test_grid_dispatch_offset_reads_substream(session):
 # ------------------------------------------------------- pairstream family
 
 def test_pairstream_null_is_calibrated():
-    with G.x64():
+    with x64():
         bits = G.splitmix64_block(3, 5, 8192)
     for mode in ("corr", "hamcorr", "match", "shift"):
         _, p = pairstream(bits, n=4096, mode=mode)
@@ -89,7 +90,7 @@ def test_pairstream_null_is_calibrated():
 def test_pairstream_catches_duplicated_stream():
     """If the two halves are the SAME words (span-0 overlap bug), the
     match mode must blow up."""
-    with G.x64():
+    with x64():
         half = G.splitmix64_block(3, 5, 4096)
     bits = np.concatenate([np.asarray(half), np.asarray(half)])
     _, p = pairstream(bits, n=4096, mode="match")
@@ -99,7 +100,7 @@ def test_pairstream_catches_duplicated_stream():
 def test_pairstream_catches_off_by_k_seam():
     """An off-by-two seam (stream s+1 starting 2 words early) is exactly
     what the shift mode exists for."""
-    with G.x64():
+    with x64():
         blk = np.asarray(G.splitmix64_block(3, 5, 8194))
     bits = np.concatenate([blk[:4096], blk[4094:8190]])
     _, p = pairstream(bits, n=4096, mode="shift")

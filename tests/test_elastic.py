@@ -80,14 +80,13 @@ def test_idle_slot_generation_is_gated():
     before any bit block exists), and real jobs are untouched."""
     entries = build_battery("smallcrush", 0.0625)
     job = _job_fn(entries)
-    with jax.experimental.enable_x64():
-        jaxpr = str(jax.make_jaxpr(job)(
-            np.int32(-1), np.int32(0), np.int32(0)))
-        assert "cond" in jaxpr                  # generation is branched
-        stat, p = jax.jit(job)(np.int32(-1), np.int32(0), np.int32(0))
-        assert float(stat) == 0.0 and np.isnan(float(p))
-        stat0, p0 = jax.jit(job)(np.int32(0), np.int32(7), np.int32(0))
-        assert np.isfinite(float(stat0)) and 0.0 <= float(p0) <= 1.0
+    jaxpr = str(jax.make_jaxpr(job)(
+        np.int32(-1), np.int32(0), np.int32(0)))
+    assert "cond" in jaxpr                      # generation is branched
+    stat, p = jax.jit(job)(np.int32(-1), np.int32(0), np.int32(0))
+    assert float(stat) == 0.0 and np.isnan(float(p))
+    stat0, p0 = jax.jit(job)(np.int32(0), np.int32(7), np.int32(0))
+    assert np.isfinite(float(stat0)) and 0.0 <= float(p0) <= 1.0
 
 
 # --------------------------------------------------- checkpoint v3 layout
@@ -206,6 +205,12 @@ def scenario(tmp_path_factory):
 
 def test_resize_bitwise_single_generator(scenario):
     assert scenario["single_bitwise"]
+
+
+def test_width_one_matches_wide_pool_bitwise(scenario):
+    """A 1-worker pool and an 8-worker pool stitch bitwise-equal p-values
+    (no statistic may depend on how the round program was partitioned)."""
+    assert scenario["width1_bitwise"]
 
 
 def test_resize_bitwise_fanout(scenario):

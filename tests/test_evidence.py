@@ -22,8 +22,7 @@ Four layers, cheapest first:
                        refusal, borderline continuation, cache engine
                        isolation.
 
-Property tests use ``hypothesis`` when available and the deterministic
-conftest shim otherwise.
+Property tests use ``hypothesis``.
 """
 import math
 import os

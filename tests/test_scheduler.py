@@ -2,7 +2,7 @@
 import math
 
 import numpy as np
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.scheduler import make_plan, replan
 
@@ -73,6 +73,7 @@ def test_make_plan_unknown_mode_raises():
 
 
 @given(k=st.integers(2, 60), w=st.integers(2, 16), seed=st.integers(0, 10))
+@example(k=4, w=2, seed=8)   # 256-word floor: 3 parts outgrew the test
 @settings(max_examples=30, deadline=None)
 def test_over_decompose_invariants(k, w, seed):
     """Decomposition shrinks the largest job, never adds cost, covers every

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.ckpt import io as ckpt_io
+from repro.common.compat import x64
 from repro.core import stitch
 from repro.core.api import (CAMPAIGN_LEDGER_VERSION, CKPT_VERSION,
                             CampaignLedger, CampaignSpec, Checkpoint,
@@ -141,7 +142,7 @@ def test_offset_convention_single_gate():
 def test_block_offset_continuation():
     """The registry switch honours the canonical convention: None is
     the offset-free trace, an integer continues the stream exactly."""
-    with G.x64():
+    with x64():
         full = np.asarray(G.gen_block_by_id(0, 7, 3, 128, offset=None))
         head = np.asarray(G.gen_block_by_id(0, 7, 3, 64))
         tail = np.asarray(G.gen_block_by_id(0, 7, 3, 64, offset=64))

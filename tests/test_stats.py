@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.common.compat import x64
 from repro.core.battery import build_battery
 from repro.core.pool import run_sequential
 from repro.rng import generators as G
@@ -57,7 +58,7 @@ def test_pvalues_roughly_uniform(entries):
 @given(seed=st.integers(0, 1000), stream=st.integers(0, 1000))
 @settings(max_examples=20, deadline=None)
 def test_streams_deterministic_and_distinct(seed, stream):
-    with G.x64():
+    with x64():
         a = np.asarray(G.splitmix64_block(seed, stream, 64))
         b = np.asarray(G.splitmix64_block(seed, stream, 64))
         c = np.asarray(G.splitmix64_block(seed, stream + 1, 64))
@@ -68,7 +69,7 @@ def test_streams_deterministic_and_distinct(seed, stream):
 def test_counter_offset_continuation():
     """block(n=2k) == block(n=k) ++ block(n=k, offset=k) — what makes
     sequential-reuse mode and over-decomposition exact."""
-    with G.x64():
+    with x64():
         full = np.asarray(G.splitmix64_block(5, 1, 128))
         a = np.asarray(G.splitmix64_block(5, 1, 64))
         b = np.asarray(G.splitmix64_block(5, 1, 64, offset=64))
@@ -83,7 +84,7 @@ def test_counter_offset_continuation_all(gen, seed, stream, k):
     """The continuation property must hold for EVERY counter-based
     generator at arbitrary split points, not just splitmix64 at 64."""
     fn = G.GENERATORS[gen]
-    with G.x64():
+    with x64():
         full = np.asarray(fn(seed, stream, 2 * k))
         a = np.asarray(fn(seed, stream, k))
         b = np.asarray(fn(seed, stream, k, offset=k))
@@ -100,7 +101,7 @@ def test_streams_pairwise_disjoint_first_k(gen, seed, streams):
     genuinely get fresh bits (pool.stream_table's contract)."""
     k = 64
     fn = G.GENERATORS[gen]
-    with G.x64():
+    with x64():
         blocks = {s: np.asarray(fn(seed, s, k)) for s in streams}
     items = sorted(blocks)
     for i, s1 in enumerate(items):
@@ -110,7 +111,7 @@ def test_streams_pairwise_disjoint_first_k(gen, seed, streams):
 
 def test_lcg_jump_matches_sequential():
     """O(log n) jump-ahead must equal stepping the recurrence."""
-    with G.x64():
+    with x64():
         jumped = np.asarray(G.lcg64_block(3, 2, 16), np.uint64)
         s = np.uint64(0)
         import numpy as _np
@@ -127,7 +128,7 @@ def test_lcg_jump_matches_sequential():
 
 
 def test_to_unit_range():
-    with G.x64():
+    with x64():
         bits = G.splitmix64_block(0, 0, 4096)
     u = np.asarray(G.to_unit(bits))
     assert (u >= 0).all() and (u < 1).all()
@@ -146,7 +147,7 @@ def test_chi2_sf_sanity():
 def test_kernels_uniform_signature(entries):
     """Every kernel returns finite (stat, p) on random bits — the contract
     the pool's lax.switch dispatch relies on."""
-    with G.x64():
+    with x64():
         bits = G.splitmix64_block(1, 1, 262144)   # covers kernel defaults
     for name, fn in KERNELS.items():
         stat, p = fn(bits)
