@@ -51,12 +51,14 @@ Typical use::
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple, Union
 
 import numpy as np
 
 from repro.ckpt import io as ckpt_io
+from repro.common.trace import span
 from repro.core import stitch
 from repro.core.battery import TestEntry, build_battery
 from repro.core.faults import (CorruptResultError, FaultEvent, FaultInjector,
@@ -856,6 +858,7 @@ class PoolSession:
         self._meshes: Dict[int, object] = {int(mesh.devices.size): mesh}
         self._cache: Dict[tuple, _Compiled] = {}
         self.trace_counts: Dict[tuple, int] = {}
+        self._run_ids = itertools.count()   # BatteryRun.run_id
 
     @property
     def n_workers(self) -> int:
@@ -1002,6 +1005,8 @@ class BatteryRun:
     def __init__(self, session: PoolSession, spec: RunSpec):
         self.session = session
         self.spec = spec
+        # the run's id in the session: the ``run`` arg of its spans
+        self.run_id = next(session._run_ids)
         self._compiled = session._compiled(spec)
         self._t0 = time.time()
         self.rounds_run = 0
@@ -1111,25 +1116,40 @@ class BatteryRun:
         look: decided generators leave the gen_ids axis, and the queue is
         dropped entirely once no generator remains undecided. A session
         ``resize()`` since the last poll is absorbed here: the residual
-        rounds replan onto the new width before anything dispatches."""
-        self._sync_width()
-        self._auto_cancel()
-        if self._queue:
-            row = self._queue.pop(0)
-            self._dispatch(row)
-            self.rounds_run += 1
-            self._update_verdicts()
-            if self.spec.verdict_engine == "evalue":
-                for g, v in enumerate(self._verdicts):
-                    self.wealth_history[g].append(v.wealth)
-            self._auto_cancel()
-            self._save_checkpoint()
-            if self.spec.progress:
-                emit_progress(self.spec.progress,
-                              f"  round {self.rounds_run}: "
-                              f"{self._jobs_done()}/"
-                              f"{len(self._compiled.jobs)} files generated")
-        return self.status()
+        rounds replan onto the new width before anything dispatches.
+
+        A poll with a round queued is the profiler span ``repro.round``
+        (``run``, ``round``, ``jobs``), its phases nested in it
+        (``repro.common.trace``)."""
+        if not self._queue:
+            return self.status()
+        with span("round", run=self.run_id, round=self.rounds_run) as rnd:
+            with span("round.plan"):
+                self._sync_width()
+                self._auto_cancel()
+                row = self._queue.pop(0) if self._queue else None
+            rnd.set_metadata(jobs=0 if row is None
+                             else int(np.count_nonzero(row >= 0)))
+            if row is not None:
+                self._dispatch(row)
+                self.rounds_run += 1
+                with span("round.verdict"):
+                    self._update_verdicts()
+                    if self.spec.verdict_engine == "evalue":
+                        for g, v in enumerate(self._verdicts):
+                            self.wealth_history[g].append(v.wealth)
+                    self._auto_cancel()
+                if self.spec.checkpoint_path:
+                    with span("round.checkpoint"):
+                        self._save_checkpoint()
+                if self.spec.progress:
+                    emit_progress(self.spec.progress,
+                                  f"  round {self.rounds_run}: "
+                                  f"{self._jobs_done()}/"
+                                  f"{len(self._compiled.jobs)} files "
+                                  "generated")
+            with span("round.status"):
+                return self.status()
 
     def held(self) -> List[int]:
         """Job indices with missing/invalid results once the current plan
@@ -1365,41 +1385,51 @@ class BatteryRun:
         through the prefetched-buffer program (their bits are gathered
         host-side from the memory-mapped capture), switch-backed
         positions through the classic generator switch — at most one
-        device dispatch per family per round."""
+        device dispatch per family per round. Every path runs the same
+        spans: ``repro.round.plan`` (runners and arguments),
+        ``repro.round.launch`` (the calls), ``repro.round.wait`` (the
+        results back on the host) and ``repro.round.fold``."""
         active = self._active()
         if not active:
             return
+        with span("round.plan"):
+            calls = self._calls(row, active)
+        with span("round.launch"):
+            outs = [runner(*args) for runner, args, _ in calls]
+        per_gen = []
+        with span("round.wait"):
+            for (_, _, positions), (stats, ps) in zip(calls, outs):
+                stats = np.asarray(stats).reshape(len(positions), -1)
+                ps = np.asarray(ps).reshape(len(positions), -1)
+                per_gen += [(g, stats[a], ps[a])
+                            for a, g in enumerate(positions)]
+        with span("round.fold"):
+            self._fold(row, per_gen)
+
+    def _calls(self, row: np.ndarray, active: List[int]) -> list:
+        """The round's runner calls as ``(runner, args, positions)``: one
+        through the generator switch for the switch-backed positions, one
+        through the prefetched-buffer program for the captured ones. A
+        call's results have one row per position, in order (a single
+        generator's runner returns that row alone)."""
         srcs = self.spec.sources
         switched = [g for g in active if not srcs[g].captured]
         captured = [g for g in active if srcs[g].captured]
-        per_gen = []
+        calls = []
         if switched:
             runner = self.session._runner(self.spec, n_gens=len(switched))
+            seeds = np.asarray([self.spec.seeds[g] for g in switched],
+                               np.int32)
+            gids = np.asarray([srcs[g].gen_id for g in switched], np.int32)
             if self.spec.offsets is not None:
-                seeds = np.asarray([self.spec.seeds[g] for g in switched],
-                                   np.int32)
-                gids = np.asarray([srcs[g].gen_id for g in switched],
-                                  np.int32)
                 offs = split_offsets([self.spec.offsets[g]
                                       for g in switched])
-                stats, ps = runner(row, seeds, gids, offs)
-                stats, ps = np.asarray(stats), np.asarray(ps)
-                per_gen += [(g, stats[a], ps[a])
-                            for a, g in enumerate(switched)]
+                args = (row, seeds, gids, offs)
             elif len(switched) == 1:
-                g0 = switched[0]
-                stats, ps = runner(row, np.int32(self.spec.seeds[g0]),
-                                   np.int32(srcs[g0].gen_id))
-                per_gen.append((g0, np.asarray(stats), np.asarray(ps)))
+                args = (row, seeds[0], gids[0])
             else:
-                seeds = np.asarray([self.spec.seeds[g] for g in switched],
-                                   np.int32)
-                gids = np.asarray([srcs[g].gen_id for g in switched],
-                                  np.int32)
-                stats, ps = runner(row, seeds, gids)
-                stats, ps = np.asarray(stats), np.asarray(ps)
-                per_gen += [(g, stats[a], ps[a])
-                            for a, g in enumerate(switched)]
+                args = (row, seeds, gids)
+            calls.append((runner, args, switched))
         if captured:
             runner = self.session._runner(self.spec, n_gens=len(captured),
                                           captured=True)
@@ -1407,13 +1437,14 @@ class BatteryRun:
                       None if self.spec.offsets is None
                       else self.spec.offsets[g]) for g in captured]
             bits = gather_captured_bits(self._compiled.jobs, row, lanes)
-            stats, ps = runner(row, bits)
-            stats, ps = np.asarray(stats), np.asarray(ps)
-            per_gen += [(g, stats[a], ps[a])
-                        for a, g in enumerate(captured)]
-        # ---- fault domain (DESIGN.md §12): everything below is host-side
+            calls.append((runner, (row, bits), captured))
+        return calls
+
+    def _fold(self, row: np.ndarray, per_gen: list) -> None:
+        """Fold a round's ``(position, stats, ps)`` into the results."""
+        # ---- fault domain (DESIGN.md §12): all of this is host-side
         # post-processing of materialised numpy results — the compiled
-        # runners above never see a fault, a gate, or a quarantine
+        # runners never see a fault, a gate, or a quarantine
         injected: List[FaultEvent] = []
         resize_to: Optional[int] = None
         if self._injector is not None:
@@ -1609,16 +1640,17 @@ class BatteryRun:
 
     def _finalize(self) -> Union[RunResult, BatteryResult]:
         wall = time.time() - self._t0
-        self._update_verdicts()
-        per_pos = self.results_by_position()
-        runs: Dict[str, RunResult] = {}
-        for g, gen in enumerate(self.spec.generators):
-            combined = per_pos[g]
-            rep = stitch.report(self._compiled.entries, combined, gen,
-                                self.spec.seeds[g])
-            runs[gen] = RunResult(combined, rep, self.rounds_run,
-                                  self.retries, wall, self.plan_rounds,
-                                  verdict=self._verdicts[g])
+        with span("finalize", run=self.run_id):
+            self._update_verdicts()
+            per_pos = self.results_by_position()
+            runs: Dict[str, RunResult] = {}
+            for g, gen in enumerate(self.spec.generators):
+                combined = per_pos[g]
+                rep = stitch.report(self._compiled.entries, combined, gen,
+                                    self.spec.seeds[g])
+                runs[gen] = RunResult(combined, rep, self.rounds_run,
+                                      self.retries, wall, self.plan_rounds,
+                                      verdict=self._verdicts[g])
         if self.spec.n_generators == 1:
             return runs[self.spec.generators[0]]
         return BatteryResult(self.spec, runs, self.rounds_run, self.retries,

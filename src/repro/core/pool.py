@@ -41,6 +41,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.common.compat import x64
+from repro.common.trace import scope
 from repro.core.battery import TestEntry
 from repro.rng.sources import switch_block
 
@@ -99,10 +100,16 @@ def _kernels(entries: List[TestEntry]):
     """The uniform kernel switch table: every test as ``bits ->
     (float32 stat, float32 p)`` — shared by the generator-switch job and
     the captured-buffer job so both dispatch paths score bits
-    identically (the ingest parity guarantee)."""
-    return [lambda bits, e=e: tuple(
-        jnp.asarray(v, jnp.float32) for v in e.kernel(bits))
-        for e in entries]
+    identically (the ingest parity guarantee). Each kernel's device ops
+    are scoped ``repro.test.<family>`` (``repro.test.custom`` for an
+    entry with no family name)."""
+    def kernel(e):
+        def run(bits):
+            with scope("test." + (e.kname or "custom")):
+                return tuple(jnp.asarray(v, jnp.float32)
+                             for v in e.kernel(bits))
+        return run
+    return [kernel(e) for e in entries]
 
 
 def _job_fn(entries: List[TestEntry], with_offset: bool = False,
@@ -129,11 +136,12 @@ def _job_fn(entries: List[TestEntry], with_offset: bool = False,
     the battery-wide ``max_words`` block a 160k-word coupon/poker job
     needs (the block is zero-padded to the widest bucket so the kernel
     switch sees one static shape, but padding is a broadcast, not
-    generator work). Idle slots (``job_id == -1``) take a zero-length
-    sentinel path: the outer ``lax.cond`` returns ``(0, nan)`` directly,
-    so a padded round pays neither generation NOR kernel work — no
-    ``n_words`` zero block is ever materialized or routed through the
-    kernel switch. Both the cond predicate and the switch indices are
+    generator work); a bucket's generation and its pad are scoped
+    ``repro.gen`` on the device. Idle slots (``job_id == -1``) take a
+    zero-length sentinel path: the outer ``lax.cond`` returns ``(0, nan)``
+    directly, so a padded round pays neither generation NOR kernel work
+    — no ``n_words`` zero block is ever materialized or routed through
+    the kernel switch. Both the cond predicate and the switch indices are
     per-shard scalars, and the fan-out runners map (``lax.map``), not
     vmap, the job over generator lanes, so every branch stays a real
     branch: a vmapped switch on a batched generator index would run all
@@ -147,15 +155,16 @@ def _job_fn(entries: List[TestEntry], with_offset: bool = False,
 
     def gen_branch(nb):
         def gen(seed, gen_id, stream, offset=None):
-            with x64():
-                if offset is not None:
-                    offset = ((offset[0].astype(jnp.uint64) << 32)
-                              | offset[1].astype(jnp.uint64))
-                block = provider(gen_id, seed, stream, nb, offset)
-            if nb < n_max:
-                block = jnp.concatenate(
-                    [block, jnp.zeros((n_max - nb,), jnp.uint32)])
-            return block
+            with scope("gen"):
+                with x64():
+                    if offset is not None:
+                        offset = ((offset[0].astype(jnp.uint64) << 32)
+                                  | offset[1].astype(jnp.uint64))
+                    block = provider(gen_id, seed, stream, nb, offset)
+                if nb < n_max:
+                    block = jnp.concatenate(
+                        [block, jnp.zeros((n_max - nb,), jnp.uint32)])
+                return block
         return gen
     gen_branches = [gen_branch(nb) for nb in sizes]
 
