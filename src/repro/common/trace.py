@@ -34,7 +34,9 @@ its span):
 
 Scopes (``core/pool.py``): ``repro.gen`` (a job's bit block and its zero
 pad) and ``repro.test.<family>`` (a test kernel; ``repro.test.custom``
-for an entry with no family name).
+for an entry with no family name). Inside ``repro.test.coupon``, each
+pass of coupon's blocked scan is ``repro.test.coupon.pass``
+(``stats/tests.py``).
 """
 from __future__ import annotations
 
