@@ -19,8 +19,9 @@ Spans (``core/api.py``, ``BatteryRun``; the children of a round nest in
 its span):
 
 - ``repro.round``: one ``poll`` that dispatches a round, with the run's
-  session-wide id ``run``, the round's index ``round`` and its non-idle
-  slots ``jobs``;
+  session-wide id ``run``, the round's index ``round``, its non-idle
+  slots ``jobs`` and the generator lanes it dispatches ``lanes`` (the
+  run's undecided generators; 0 when nothing is dispatched);
 - ``repro.round.plan``: choosing the row, the runner and its arguments;
 - ``repro.round.launch``: the runner calls, up to their return;
 - ``repro.round.wait``: copying the results back, waiting for the device;
@@ -36,7 +37,10 @@ Scopes (``core/pool.py``): ``repro.gen`` (a job's bit block and its zero
 pad) and ``repro.test.<family>`` (a test kernel; ``repro.test.custom``
 for an entry with no family name). Inside ``repro.test.coupon``, each
 pass of coupon's blocked scan is ``repro.test.coupon.pass``
-(``stats/tests.py``).
+(``stats/tests.py``). In the runners that map the job over generator
+lanes (``make_fanout_runner``, ``make_grid_runner``) each lane's job is
+``repro.lane``, outside its ``repro.gen`` and ``repro.test.<family>``, so
+those stay the innermost scopes.
 """
 from __future__ import annotations
 

@@ -1119,7 +1119,7 @@ class BatteryRun:
         rounds replan onto the new width before anything dispatches.
 
         A poll with a round queued is the profiler span ``repro.round``
-        (``run``, ``round``, ``jobs``), its phases nested in it
+        (``run``, ``round``, ``jobs``, ``lanes``), its phases nested in it
         (``repro.common.trace``)."""
         if not self._queue:
             return self.status()
@@ -1128,8 +1128,9 @@ class BatteryRun:
                 self._sync_width()
                 self._auto_cancel()
                 row = self._queue.pop(0) if self._queue else None
-            rnd.set_metadata(jobs=0 if row is None
-                             else int(np.count_nonzero(row >= 0)))
+            rnd.set_metadata(
+                jobs=0 if row is None else int(np.count_nonzero(row >= 0)),
+                lanes=0 if row is None else len(self._active()))
             if row is not None:
                 self._dispatch(row)
                 self.rounds_run += 1

@@ -219,6 +219,14 @@ def make_round_runner(entries: List[TestEntry], mesh,
     return jax.jit(round_fn)
 
 
+def _lane(job, job_id, args):
+    """One generator lane of a mapped round: the worker's job on the
+    lane's arguments, its device ops scoped ``repro.lane`` (around the
+    job's ``repro.gen`` and ``repro.test.<family>``)."""
+    with scope("lane"):
+        return job(job_id, *args)
+
+
 def make_fanout_runner(entries: List[TestEntry], mesh,
                        on_trace: Optional[Callable[[], None]] = None,
                        block_provider: Optional[Callable] = None):
@@ -233,7 +241,8 @@ def make_fanout_runner(entries: List[TestEntry], mesh,
     def round_fn(jobs, seeds, gen_ids):
         if on_trace is not None:
             on_trace()
-        stat, p = jax.lax.map(lambda a: job(jobs[0], *a), (seeds, gen_ids))
+        stat, p = jax.lax.map(lambda a: _lane(job, jobs[0], a),
+                              (seeds, gen_ids))
         return stat[:, None], p[:, None]
 
     return jax.jit(round_fn)
@@ -264,7 +273,7 @@ def make_grid_runner(entries: List[TestEntry], mesh,
     def round_fn(jobs, seeds, gen_ids, offsets):
         if on_trace is not None:
             on_trace()
-        stat, p = jax.lax.map(lambda a: job(jobs[0], *a),
+        stat, p = jax.lax.map(lambda a: _lane(job, jobs[0], a),
                               (seeds, gen_ids, offsets))
         return stat[:, None], p[:, None]
 

@@ -118,20 +118,28 @@ def test_checkpoint_resume_runs_only_missing(tmp_path):
 
 # ------------------------------------------------------------------ fan-out
 
-def test_multi_generator_fanout_matches_single_runs(session):
-    """G generators in one dispatch == the same generators run alone."""
-    spec = RunSpec("smallcrush", ("splitmix64", "pcg32", "randu"), 7,
-                   scale=SCALE)
+G8 = ("splitmix64", "msweyl", "threefry", "pcg32", "lcg64", "xorshift64s",
+      "randu", "minstd")
+
+
+@pytest.mark.parametrize("gens, seeds", [
+    (("splitmix64", "pcg32", "randu"), (7, 7, 7)),
+    (G8, tuple(101 + 7 * k for k in range(len(G8)))),
+], ids=["three", "eight"])
+def test_multi_generator_fanout_matches_single_runs(session, gens, seeds):
+    """G generators in one dispatch == the same generators run alone,
+    each with its lane's seed: every (stat, p) bitwise."""
+    spec = RunSpec("smallcrush", gens, seeds, scale=SCALE)
     multi = session.submit(spec).result()
     assert isinstance(multi, BatteryResult)
-    assert set(multi.runs) == {"splitmix64", "pcg32", "randu"}
-    for gen in spec.generators:
-        single = session.submit(RunSpec("smallcrush", gen, 7,
+    assert list(multi.runs) == list(gens)
+    for gen, seed in zip(gens, seeds):
+        single = session.submit(RunSpec("smallcrush", gen, seed,
                                         scale=SCALE)).result()
         for i in range(10):
-            assert np.isclose(multi.runs[gen].results[i][1],
-                              single.results[i][1], rtol=1e-6,
-                              equal_nan=True), (gen, i)
+            lane = np.asarray(multi.runs[gen].results[i], np.float64)
+            alone = np.asarray(single.results[i], np.float64)
+            assert lane.tobytes() == alone.tobytes(), (gen, i)
     assert multi.runs["randu"].n_suspect >= 2    # canary still flagged
     assert multi.runs["splitmix64"].n_suspect == 0
 

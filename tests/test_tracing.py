@@ -1,9 +1,11 @@
 """The program's profiler spans and device scopes (``repro.common.trace``):
 a profiled run shows one ``repro.round`` span per dispatching poll, with
-its ``run``/``round``/``jobs`` args and its phases nested inside, on
-every dispatch path; the compiled round program names generation and
-every test family in its HLO metadata."""
+its ``run``/``round``/``jobs``/``lanes`` args and its phases nested
+inside, on every dispatch path; the compiled round program names
+generation and every test family in its HLO metadata, inside
+``repro.lane`` where the job is mapped over generator lanes."""
 import glob
+import re
 
 import jax
 import numpy as np
@@ -45,7 +47,7 @@ def _profile(tmp_path, body):
                 if ev.name.startswith("repro."):
                     out.append((ev.name, int(ev.start_ns), int(ev.end_ns),
                                 {k: v for k, v in ev.stats
-                                 if k in ("run", "round", "jobs")}))
+                                 if k in ("run", "round", "jobs", "lanes")}))
     return sorted(out, key=lambda e: e[1])
 
 
@@ -66,11 +68,11 @@ def _spec(kind, capture, **kw):
 @pytest.mark.parametrize("kind", ["single", "fanout", "grid", "captured"])
 def test_every_dispatch_path_spans_its_rounds(session, capture, tmp_path,
                                               kind):
-    """One ``repro.round`` per poll with the run's id, the round's index
-    and its non-idle slots; each phase once inside its round (plan twice:
-    the row, then the runner and its arguments); no checkpoint span
-    without a checkpoint path; ``repro.finalize`` once, after the
-    rounds."""
+    """One ``repro.round`` per poll with the run's id, the round's index,
+    its non-idle slots and its generator lanes; each phase once inside
+    its round (plan twice: the row, then the runner and its arguments);
+    no checkpoint span without a checkpoint path; ``repro.finalize``
+    once, after the rounds."""
     spec = _spec(kind, capture)
     session.submit(spec).poll()                 # compile outside the trace
     rows, runs = [], []
@@ -87,8 +89,10 @@ def test_every_dispatch_path_spans_its_rounds(session, capture, tmp_path,
     events = _profile(tmp_path, body)
     run, = runs
     rounds = [e for e in events if e[0] == "repro.round"]
+    lanes = len(spec.generators) if kind in ("fanout", "grid") else 1
     assert [e[3] for e in rounds] == [{"run": run.run_id, "round": k,
-                                       "jobs": j} for k, j in enumerate(rows)]
+                                       "jobs": j, "lanes": lanes}
+                                      for k, j in enumerate(rows)]
     assert len(rounds) == run.rounds_run > 0
     for _, a, b, _ in rounds:
         inside = [e[0] for e in events if e[0].startswith("repro.round.")
@@ -161,3 +165,28 @@ def test_round_program_names_generation_and_every_family(session):
     assert "/repro.gen/" not in text
     for fam in families:
         assert f"/repro.test.{fam}/" in text, fam
+
+
+@pytest.mark.parametrize("kind", ["fanout", "grid"])
+def test_mapped_programs_scope_each_lane(session, capture, kind):
+    """In the runners that map the job over generator lanes, every
+    generation and kernel op sits in ``repro.lane``, outside its
+    ``repro.gen`` or ``repro.test.<family>``; the one-lane round program
+    has no lane scope."""
+    spec = _spec(kind, capture)
+    n = len(spec.generators)
+    row = np.zeros((session.n_workers,), np.int32)
+    args = (row, np.full(n, 7, np.int32), np.arange(n, dtype=np.int32))
+    if kind == "grid":
+        args += (np.zeros((n, 2), np.uint32),)
+    text = session._runner(spec).lower(*args).compile().as_text()
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', text)]
+    inner = [[k for k, c in enumerate(p)
+              if c == "repro.gen" or c.startswith("repro.test.")]
+             for p in paths]
+    scoped = [(p, k[0]) for p, k in zip(paths, inner) if k]
+    assert any("repro.gen" in p for p, _ in scoped)
+    assert scoped and all("repro.lane" in p[:k] for p, k in scoped)
+    one = session._runner(_spec("single", capture))
+    text = one.lower(row, np.int32(7), np.int32(0)).compile().as_text()
+    assert "/repro.gen/" in text and "repro.lane" not in text
